@@ -226,7 +226,7 @@ def _paged_kernel_lse(bt_ref, len_ref, q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0] = (acc_scr[...] / l[:, None]).astype(o_ref.dtype)
         # lse = m + log(l): exactly -inf-ish (_NEG + log(1e-30)) for a
         # fully-masked stripe, so its merge weight underflows to 0
-        lse_ref[0] = (m_scr[...] + jnp.log(l)).astype(lse_ref.dtype)
+        lse_ref[0] = (m_scr[...] + jnp.log(l))[:, None].astype(lse_ref.dtype)
 
 
 def decode_attention_paged_kernel(q, k_pool, v_pool, block_tables,
@@ -302,7 +302,9 @@ def decode_attention_paged_lse_kernel(q, k_pool, v_pool, block_tables,
         ],
         out_specs=[
             pl.BlockSpec((1, h, dh), lambda bi, si, bt, cl: (bi, 0, 0)),
-            pl.BlockSpec((1, h), lambda bi, si, bt, cl: (bi, 0)),
+            # (B, H, 1): a (1, H) block of a (B, H) array breaks the
+            # TPU's (8, 128) tiling rule on its first dim
+            pl.BlockSpec((1, h, 1), lambda bi, si, bt, cl: (bi, 0, 0)),
         ],
         scratch_shapes=[
             pltpu.VMEM((h,), jnp.float32),
@@ -311,13 +313,14 @@ def decode_attention_paged_lse_kernel(q, k_pool, v_pool, block_tables,
         ],
     )
 
-    return pl.pallas_call(
+    out, lse = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct((b, h, dh), q.dtype),
-            jax.ShapeDtypeStruct((b, h), jnp.float32),
+            jax.ShapeDtypeStruct((b, h, 1), jnp.float32),
         ],
         interpret=interpret,
     )(block_tables.astype(jnp.int32), cache_len.astype(jnp.int32),
       q, k_pool, v_pool)
+    return out, lse[..., 0]

@@ -9,6 +9,7 @@ from __future__ import annotations
 
 import jax
 import numpy as np
+from jax.experimental import mesh_utils
 
 __all__ = ["make_production_mesh", "make_local_mesh"]
 
@@ -43,5 +44,11 @@ def make_local_mesh(*, tp: int = 1, data: int = 1):
             "on a CPU dev box set XLA_FLAGS="
             f"--xla_force_host_platform_device_count={need} before "
             "importing jax")
-    devices = np.array(jax.devices()[:need]).reshape(data, tp)
+    if need == have:
+        # the whole host: order devices along the physical interconnect
+        # (on a v5e 2x2, ids 0,1,3,2 form the ring; list order would
+        # put a diagonal, two-hop link inside the 'model' axis)
+        devices = mesh_utils.create_device_mesh((data, tp))
+    else:
+        devices = np.array(jax.devices()[:need]).reshape(data, tp)
     return jax.sharding.Mesh(devices, ("data", "model"))

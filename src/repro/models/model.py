@@ -42,8 +42,10 @@ class Model:
             return encdec_template(self.cfg)
         return decoder_template(self.cfg)
 
-    def init(self, key):
-        return init_from_template(self.template(), key)
+    def init(self, key, shardings=None):
+        """Seeded parameters, built on the devices ``shardings`` names
+        (a tree matching ``template()``; None = the default device)."""
+        return init_from_template(self.template(), key, shardings=shardings)
 
     def param_specs(self):
         return specs_from_template(self.template())
@@ -127,10 +129,15 @@ class Model:
                            n_slots: int):
         return paged_cache_shapes(self.cfg, n_pages, page_size, n_slots)
 
-    def init_paged_cache(self, n_pages: int, page_size: int, n_slots: int):
-        return jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
-                            self.paged_cache_shapes(n_pages, page_size,
-                                                    n_slots))
+    def init_paged_cache(self, n_pages: int, page_size: int, n_slots: int,
+                         shardings=None):
+        """Zeroed paged cache, built on the devices ``shardings`` names
+        (a tree matching ``paged_cache_shapes``; None = default device)."""
+        shapes = self.paged_cache_shapes(n_pages, page_size, n_slots)
+        return jax.jit(
+            lambda: jax.tree.map(lambda s: jnp.zeros(s.shape, s.dtype),
+                                 shapes),
+            out_shardings=shardings)()
 
     def decode_step_paged(self, params, token, cache, cache_len,
                           block_tables, *, page_size: int):

@@ -93,13 +93,23 @@ def _wo_proj(cfg, p, o):
     GSPMD emits the standard Megatron row-parallel psum: the "single
     psum per block" of the efficient decode plan falls out of this
     decomposition for free.
+
+    The partials come out group-leading, (g, B, S, D): that is
+    dot_general's own batch-first order, so no transpose follows the
+    dot.  XLA's CPU dot thunk rejects the group-inner layout
+    (``bsgk,gkf->bsgf``) for bf16 operands with f32 accumulation.
     """
     b, s, h, dh = o.shape
     g = cfg.n_kv_heads
     w = p["wo"].reshape(g, (h // g) * dh, -1)
-    partial = jnp.einsum("bsgk,gkf->bsgf", o.reshape(b, s, g, (h // g) * dh),
+    partial = jnp.einsum("bsgk,gkf->gbsf", o.reshape(b, s, g, (h // g) * dh),
                          w, preferred_element_type=jnp.float32)
-    return gather_model(partial).sum(axis=2).astype(o.dtype)
+    # the barrier keeps the TPU compiler from folding the group sum into
+    # the dot's contraction when every group is local (one device): the
+    # sharded program cannot fold across its gather, so folding would
+    # give one device a different accumulation order than the mesh
+    partial = jax.lax.optimization_barrier(partial)
+    return gather_model(partial).sum(axis=0).astype(o.dtype)
 
 
 def _pin_qkv(q, k, v):

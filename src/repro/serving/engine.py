@@ -44,9 +44,9 @@ the batch, a per-slot block table mapping logical positions to physical
 pages (page 0 = scratch, where masked lanes write), and a host swap pool
 holding preempted requests' KV.  See docs/serving_engine.md.
 
-The engine is single-host (the real CpuDevice here; a TPU slice in
-production — the jitted step functions are the same ones the dry-run
-lowers for the production mesh).
+The engine is single-host: the CPU in tests, one TPU chip or a
+four-chip host's mesh on the chip (``chip_smoke.py`` at the repo root
+runs both).
 """
 
 from __future__ import annotations
@@ -190,21 +190,26 @@ class ServingEngine:
             block_size=self.block_size,
             swap_capacity_tokens=self.swap_capacity_tokens)
         self._preflight_memory()
+        # parameters and pool are built where they live: on the plan's
+        # shardings on a mesh, on the default device otherwise.  Pool
+        # pages live per-shard (split over the kv-head dim); the
+        # host-side block tables below stay authoritative and
+        # shard-agnostic
         if self.params is None:
-            self.params = self.model.init(jax.random.PRNGKey(self.seed))
-        if self.plan is not None:
+            self.params = self.model.init(
+                jax.random.PRNGKey(self.seed),
+                None if self.plan is None else self.plan.param_shardings)
+        elif self.plan is not None:
             self.params = self.plan.place_params(self.params)
         if self.service_model is None:
             self.service_model = ServiceModel()
         self.metrics = EngineMetrics()
         self._rng = np.random.default_rng(self.seed)
-        self._cache = self.model.init_paged_cache(
+        shapes = self.model.paged_cache_shapes(
             self.kv.pool_blocks, self.block_size, self.n_slots)
-        if self.plan is not None:
-            # pool pages live per-shard from here on (split over the
-            # kv-head dim); the host-side block tables below stay
-            # authoritative and shard-agnostic
-            self._cache = self.plan.place_cache(self._cache)
+        self._cache = self.model.init_paged_cache(
+            self.kv.pool_blocks, self.block_size, self.n_slots,
+            None if self.plan is None else self.plan.cache_shardings(shapes))
         self._has_kv = "k" in self._cache
         self._max_pages = -(-self.max_seq_len // self.block_size)
         self._block_tables = np.full((self.n_slots, self._max_pages),
@@ -1100,14 +1105,9 @@ class ServingEngine:
 
     @property
     def fused_compile_count(self) -> int:
-        """Actual XLA compile count of the fused step (jit cache size).
-
-        Reads jax's (private, but the only per-function counter there
-        is) ``PjitFunction._cache_size``; returns -1 if a jax upgrade
-        removes it, so bound checks degrade to vacuous-pass instead of
-        crashing CI (the compile-counter tests skip on -1)."""
-        counter = getattr(self._fused_fn, "_cache_size", None)
-        return counter() if counter is not None else -1
+        """Actual XLA compile count of the fused step: jax's (private,
+        but the only per-function counter there is) jit cache size."""
+        return self._fused_fn._cache_size()
 
     def max_fused_compiles(self, n_steps_variants: int = 1) -> int:
         """Upper bound on fused-step compiles: the bucket-ladder product.

@@ -152,19 +152,19 @@ class ShardingPlan:
     def place_params(self, params):
         return jax.device_put(params, self.param_shardings)
 
+    def cache_shardings(self, cache: dict) -> dict:
+        """Plan layout of a paged-cache dict (arrays or shapes): k/v on
+        the pool sharding, recurrent state replicated."""
+        return {key: (self.kv_pool if key in ("k", "v") else
+                      jax.tree.map(lambda _: self.replicated, val))
+                for key, val in cache.items()}
+
     def place_cache(self, cache: dict) -> dict:
-        """Commit a paged-cache dict to the plan layout.  Also used to
-        re-pin the pool after eager host-side updates (swap restore)
-        whose sharding propagation is XLA's choice, not ours — a no-op
-        copy when the layout already matches."""
-        out = {}
-        for key, val in cache.items():
-            if key in ("k", "v"):
-                out[key] = jax.device_put(val, self.kv_pool)
-            else:
-                out[key] = jax.tree.map(
-                    lambda a: jax.device_put(a, self.replicated), val)
-        return out
+        """Commit a paged-cache dict to the plan layout.  Used to re-pin
+        the pool after eager host-side updates (swap restore) whose
+        sharding propagation is XLA's choice, not ours — a no-op copy
+        when the layout already matches."""
+        return jax.device_put(cache, self.cache_shardings(cache))
 
     # ------------------------------------------------- trace-time constraints
 
@@ -174,7 +174,7 @@ class ShardingPlan:
         Efficient mode: IDENTITY — leaving the hook's call sites
         unconstrained is precisely what lets GSPMD emit the Megatron
         dataflow through the unchanged model code: ``_wo_proj``'s
-        post-hook ``.sum(axis=2)`` over the group-sharded partials
+        post-hook ``.sum(axis=0)`` over the group-sharded partials
         becomes the row-parallel psum, ``_pin_qkv`` leaves q/k/v
         head-sharded off the column-parallel projections, the final
         logits stay vocab-sharded into a partitioned argmax/categorical
@@ -234,7 +234,11 @@ class ShardingPlan:
             with plan.context():
                 return jitted.lower(*args, **kwargs)
 
-        call._cache_size = getattr(jitted, "_cache_size", None)
+        # a Python closure, not jitted's bound method: the garbage
+        # collector cannot see through that nanobind object, so the
+        # cycle engine -> call -> jitted -> fn -> engine would never be
+        # freed, and with it every buffer the engine holds
+        call._cache_size = lambda: jitted._cache_size()
         call.lower = lower
         return call
 

@@ -32,6 +32,7 @@ from repro.configs import get_config
 from repro.core import (LengthDistribution, OraclePredictor, Scheduler,
                         make_policy)
 from repro.models import build_model
+from repro.models.ssm import mamba2_block
 from repro.serving import RequestState, ServeRequest, ServingEngine
 from repro.serving.engine import _ladder_size, _pow2_bucket
 
@@ -159,8 +160,6 @@ def test_compile_counter_bounded_under_churn():
     wave("a", 12)
     bound = eng.max_fused_compiles()
     first = eng.fused_compile_count
-    if first < 0:
-        pytest.skip("jax build exposes no jit cache-size counter")
     assert 0 < first <= bound
     # same shapes revisited -> the jit cache must not grow
     wave("b", 12)
@@ -222,30 +221,64 @@ def test_decode_bit_identical_across_neighbor_buckets():
 @pytest.mark.parametrize("arch", ["mamba2-2.7b", "zamba2-1.2b"])
 def test_padded_prefill_bit_identical_to_unpadded(arch):
     """ROADMAP item: the true-length mask (dt = 0 at pads, decay exactly
-    1) makes pow2-padded prefill bit-identical to the unpadded scan —
-    recurrent state, conv tail, and (hybrid) attention KV at valid
-    positions."""
+    1) makes every Mamba2 block's pow2-padded prefill bit-identical to
+    the unpadded scan — recurrent state and conv tail — even when the
+    pad rows hold garbage.
+
+    Each layer's block is fed the same valid rows in both runs.  Chained
+    through the whole model the claim would also need XLA's CPU GEMM to
+    be row-invariant in the row count, and it is not: a (23, F) and a
+    (64, F) bf16 product can round one element differently, which then
+    reaches the next layer's state.  That is the backend, not the mask.
+
+    So the whole model's padded ``prefill(lengths=...)`` — and the
+    remat branch of the same scan — is held to the unpadded one within
+    that CPU rounding: ssd to 1e-3 (drift seen 3.3e-5; pads scanned
+    without the mask move it by 2.6e-2), conv tail and attention KV to
+    two bf16 ulps at their scale of ~1.3 (drift seen one ulp; unmasked
+    pads move the conv tail by 1.8)."""
     cfg = get_config(arch, reduced=True)
     m = build_model(cfg)
     params = m.init(jax.random.PRNGKey(1))
     rng = np.random.default_rng(0)
     S = 23
+    u = rng.standard_normal((1, 64, cfg.d_model)).astype(np.float32)
+    plain = jax.jit(lambda p, x: mamba2_block(p, x, cfg)[1])
+    masked = jax.jit(lambda p, x, n: mamba2_block(p, x, cfg, lengths=n)[1])
+    for layer in range(cfg.n_layers):
+        lp = jax.tree.map(lambda a: a[layer], params["layers"]["ssm"])
+        want = plain(lp, jnp.asarray(u[:, :S], jnp.bfloat16))
+        for spad in (32, 64):
+            got = masked(lp, jnp.asarray(u[:, :spad], jnp.bfloat16),
+                         jnp.asarray([S], jnp.int32))
+            np.testing.assert_array_equal(np.asarray(want["ssd"]),
+                                          np.asarray(got["ssd"]))
+            np.testing.assert_array_equal(
+                np.asarray(want["conv"], np.float32),
+                np.asarray(got["conv"], np.float32))
+
     toks = rng.integers(3, cfg.vocab_size, (1, S)).astype(np.int32)
     _, want = m.prefill(params, {"tokens": jnp.asarray(toks)})
+    atol = {"ssd": 1e-3, "conv": 1.6e-2, "k": 1.6e-2, "v": 1.6e-2}
     for spad in (32, 64):
         tp = np.zeros((1, spad), np.int32)
         tp[0, :S] = toks
-        _, got = m.prefill(params, {
-            "tokens": jnp.asarray(tp),
-            "lengths": jnp.asarray([S], jnp.int32)})
-        np.testing.assert_array_equal(np.asarray(want["ssm"]["ssd"]),
-                                      np.asarray(got["ssm"]["ssd"]))
-        np.testing.assert_array_equal(np.asarray(want["ssm"]["conv"]),
-                                      np.asarray(got["ssm"]["conv"]))
-        if "k" in want:
-            np.testing.assert_array_equal(
-                np.asarray(want["k"], np.float32)[:, :, :S],
-                np.asarray(got["k"], np.float32)[:, :, :S])
+        batch = {"tokens": jnp.asarray(tp),
+                 "lengths": jnp.asarray([S], jnp.int32)}
+        _, prefilled = m.prefill(params, batch)
+        _, rematted, _ = m.forward(params, batch, collect_cache=True,
+                                   remat=True)
+        for got in (prefilled, rematted):
+            for key in ("ssd", "conv"):
+                np.testing.assert_allclose(
+                    np.asarray(got["ssm"][key], np.float32),
+                    np.asarray(want["ssm"][key], np.float32),
+                    rtol=0, atol=atol[key], err_msg=f"{key} spad={spad}")
+            for key in ("k", "v") if "k" in want else ():
+                np.testing.assert_allclose(
+                    np.asarray(got[key], np.float32)[:, :, :S],
+                    np.asarray(want[key], np.float32)[:, :, :S],
+                    rtol=0, atol=atol[key], err_msg=f"{key} spad={spad}")
 
 
 def test_recurrent_prefill_compiles_per_bucket_not_per_length():
@@ -267,7 +300,4 @@ def test_recurrent_prefill_compiles_per_bucket_not_per_length():
     eng.submit_batch(reqs)
     eng.run_until_done(max_steps=2000)
     assert all(r.state == RequestState.FINISHED for r in reqs)
-    counter = getattr(eng._prefill_fn, "_cache_size", None)
-    if counter is None:
-        pytest.skip("jax build exposes no jit cache-size counter")
-    assert counter() == 1
+    assert eng._prefill_fn._cache_size() == 1
