@@ -62,6 +62,7 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from ..core.scheduler import Scheduler
 from ..kernels.bucketing import ladder_size as _ladder_size
@@ -334,8 +335,8 @@ class ServingEngine:
             return buf, emitted, fin, cc(cache)
 
         self._fused_fn = fused_steps
-        # abstract (shape/dtype/sharding) args of the last fused call —
-        # lower_fused_hlo() re-lowers them for the roofline bench
+        # (nb, pb, static args) of the last fused call — what
+        # lower_fused_hlo() needs beyond the engine's own params and pool
         self._last_fused_call = None
 
     def _preflight_memory(self) -> None:
@@ -401,6 +402,7 @@ class ServingEngine:
             tenants=[r.tenant for r in requests])
         for r, arrival in zip(requests, arrivals):
             r.arrival = arrival
+            r.submitted = now
             self._requests[r.request_id] = r
 
     def abort(self, request_id: str, reason: str = "abort") -> None:
@@ -445,6 +447,8 @@ class ServingEngine:
         self._clear_slot(r)
 
     def _bind_slot(self, r: ServeRequest, slot: int) -> None:
+        if math.isnan(r.admitted):
+            r.admitted = self.clock()    # first admission only
         r.slot = slot
         self._slot_rid[slot] = r.request_id
         row = np.full(self._max_pages, SCRATCH_BLOCK, np.int32)
@@ -869,39 +873,53 @@ class ServingEngine:
     # ----------------------------------------------------------------- step
 
     def step(self) -> int:
-        """One engine iteration. Returns number of running requests."""
-        now = self.clock()
-        self.scheduler.set_now(now)
-        selected = self._select_running()
-        sel = set(selected)
+        """One engine iteration. Returns number of running requests.
 
-        # preempt displaced requests (swap mode keeps their KV on host)
-        for rid in list(self._running):
-            if rid not in sel:
-                self._preempt(self._requests[rid])
+        Each phase runs inside one ``engine.<phase>`` profiler span
+        (``select``, ``admit``, ``relieve``, ``prefill``, then
+        ``decode_prepare``/``decode_wait``/``decode_commit`` when a lane
+        decodes), so a trace names what the host did while the device
+        idled; with no profiler recording a span costs about a
+        microsecond."""
+        with TraceAnnotation("engine.select"):
+            self.scheduler.set_now(self.clock())
+            selected = self._select_running()
+            sel = set(selected)
+            # preempt displaced requests (swap mode keeps their KV on host)
+            for rid in list(self._running):
+                if rid not in sel:
+                    self._preempt(self._requests[rid])
 
         # admit newcomers: swap-ins restore KV, others (re-)prefill
-        for rid in selected:
-            r = self._requests[rid]
-            if r.state != RequestState.RUNNING:
-                try:
-                    self._admit(r)
-                except RuntimeError:
-                    if self.kv.blocks_for(r.context_len + 1) \
-                            > self.kv.n_blocks:
-                        # the context can NEVER fit the physical pool:
-                        # reject instead of livelocking in WAITING
-                        self.abort(rid, reason="infeasible_prompt")
+        with TraceAnnotation("engine.admit"):
+            for rid in selected:
+                r = self._requests[rid]
+                if r.state != RequestState.RUNNING:
+                    try:
+                        self._admit(r)
+                    except RuntimeError:
+                        if self.kv.blocks_for(r.context_len + 1) \
+                                > self.kv.n_blocks:
+                            # the context can NEVER fit the physical pool:
+                            # reject instead of livelocking in WAITING
+                            self.abort(rid, reason="infeasible_prompt")
+                            continue
+                        # transient shortfall (e.g. forced-top guard
+                        # racing an external hog): leave the request
+                        # queued
                         continue
-                    # transient shortfall (e.g. forced-top guard racing
-                    # an external hog): leave the request queued
-                    continue
 
         # capacity pressure from the previous decode's growth
-        self._relieve_pressure()
+        with TraceAnnotation("engine.relieve"):
+            self._relieve_pressure()
 
         # chunked prefill, mixed with the decode batch under one budget
-        self._run_prefills()
+        m = self.metrics
+        chunks0, tokens0 = m.prefill_chunks, m.prefill_tokens
+        with TraceAnnotation("engine.prefill") as span:
+            self._run_prefills()
+            span.set_metadata(chunks=m.prefill_chunks - chunks0,
+                              tokens=m.prefill_tokens - tokens0)
 
         if not self._running:
             return 0
@@ -933,54 +951,60 @@ class ServingEngine:
         # (or free) are masked by pointing their table rows at the scratch
         # page for this call: their lane's write lands in scratch instead
         # of clobbering KV the chunked prefill already scattered.
-        tokens = jnp.asarray(self._last_token[:, None], jnp.int32)
-        cache_len = jnp.asarray(np.maximum(self._cache_len, 0), jnp.int32)
-        tables_np = self._block_tables
-        not_ready = self._cache_len < 0
-        if not_ready.any():
-            tables_np = tables_np.copy()
-            tables_np[not_ready] = SCRATCH_BLOCK
-        tables = jnp.asarray(tables_np)
-        logits, self._cache = self._decode_fn(self.params, tokens,
-                                              self._cache, cache_len,
-                                              tables)
-        logits_np = np.asarray(logits, np.float32)
+        with TraceAnnotation("engine.decode_prepare", lanes=len(ready)):
+            tokens = jnp.asarray(self._last_token[:, None], jnp.int32)
+            cache_len = jnp.asarray(np.maximum(self._cache_len, 0),
+                                    jnp.int32)
+            tables_np = self._block_tables
+            not_ready = self._cache_len < 0
+            if not_ready.any():
+                tables_np = tables_np.copy()
+                tables_np[not_ready] = SCRATCH_BLOCK
+            tables = jnp.asarray(tables_np)
+            logits, self._cache = self._decode_fn(self.params, tokens,
+                                                  self._cache, cache_len,
+                                                  tables)
+        with TraceAnnotation("engine.decode_wait"):
+            logits_np = np.asarray(logits, np.float32)
         self.metrics.decode_iterations += 1
+        self.metrics.decode_lanes += len(ready)
 
-        slots = [s for s, _ in ready]
-        rids = [rid for _, rid in ready]
-        temps = np.array([self._requests[rid].temperature for rid in rids])
-        toks = self._sample_batch(logits_np, slots, temps)
+        with TraceAnnotation("engine.decode_commit"):
+            slots = [s for s, _ in ready]
+            rids = [rid for _, rid in ready]
+            temps = np.array([self._requests[rid].temperature
+                              for rid in rids])
+            toks = self._sample_batch(logits_np, slots, temps)
 
-        progressing, progressed = [], []
-        for slot, rid, tok in zip(slots, rids, toks):
-            r = self._requests[rid]
-            tok = int(tok)
-            self._cache_len[slot] += 1
-            self._last_token[slot] = tok
-            r.output_tokens.append(tok)
-            self.metrics.decode_tokens += 1
-            if np.isnan(r.ttft):
-                r.ttft = self.clock() - r.arrival
-            if tok == r.eos_token:
-                self._finish(r, reason="eos")
-                continue
-            if r.generated >= r.max_new_tokens \
-                    or r.context_len >= self.max_seq_len - 1:
-                self._finish(r, reason="length")
-                continue
-            progressing.append(rid)
-            progressed.append(r.generated)
-            # reserve the next token's block now; a False return is
-            # surfaced as capacity pressure and forces eviction at the
-            # next select (previously this return value was dropped and
-            # over-capacity growth went unaccounted)
-            if self.kv.grow(rid, 1):
-                self._sync_block_table(r)
-            else:
-                self.metrics.grow_failures += 1
-                self._needs_grow.add(rid)
-        self.scheduler.on_progress_many(progressing, progressed)
+            progressing, progressed = [], []
+            for slot, rid, tok in zip(slots, rids, toks):
+                r = self._requests[rid]
+                tok = int(tok)
+                self._cache_len[slot] += 1
+                self._last_token[slot] = tok
+                r.output_tokens.append(tok)
+                self.metrics.decode_tokens += 1
+                if np.isnan(r.ttft):
+                    r.ttft = self.clock() - r.arrival
+                if tok == r.eos_token:
+                    self._finish(r, reason="eos")
+                    continue
+                if r.generated >= r.max_new_tokens \
+                        or r.context_len >= self.max_seq_len - 1:
+                    self._finish(r, reason="length")
+                    continue
+                progressing.append(rid)
+                progressed.append(r.generated)
+                # reserve the next token's block now; a False return is
+                # surfaced as capacity pressure and forces eviction at
+                # the next select (previously this return value was
+                # dropped and over-capacity growth went unaccounted)
+                if self.kv.grow(rid, 1):
+                    self._sync_block_table(r)
+                else:
+                    self.metrics.grow_failures += 1
+                    self._needs_grow.add(rid)
+            self.scheduler.on_progress_many(progressing, progressed)
 
     def _decode_fused(self, ready: list[tuple[int, str]]) -> None:
         """Fused decode: ONE jitted, donated device call advances every
@@ -995,111 +1019,111 @@ class ServingEngine:
         pow2 ladder, so batch/page churn never changes the traced shapes
         beyond the bounded bucket set."""
         n_steps = self.decode_steps
-        # per-lane step budgets: cap = tokens until forced finish
-        # (max_new_tokens / max_seq_len), grant = KV reserved ahead of the
-        # call (a short grant pauses the lane rather than overrunning)
-        plan = []                              # (slot, rid, budget, cap)
-        for slot, rid in ready:
-            r = self._requests[rid]
-            cap = min(r.max_new_tokens - r.generated,
-                      (self.max_seq_len - 1) - r.context_len)
-            cap = max(1, cap)
-            want = min(n_steps, cap)
-            grant = self.kv.grow_upto(rid, want - 1) if want > 1 else 0
-            if grant:
-                self._sync_block_table(r)
-            plan.append((slot, rid, grant + 1, cap))
+        with TraceAnnotation("engine.decode_prepare",
+                             lanes=len(ready)) as span:
+            # per-lane step budgets: cap = tokens until forced finish
+            # (max_new_tokens / max_seq_len), grant = KV reserved ahead of
+            # the call (a short grant pauses the lane rather than
+            # overrunning)
+            plan = []                          # (slot, rid, budget, cap)
+            for slot, rid in ready:
+                r = self._requests[rid]
+                cap = min(r.max_new_tokens - r.generated,
+                          (self.max_seq_len - 1) - r.context_len)
+                cap = max(1, cap)
+                want = min(n_steps, cap)
+                grant = self.kv.grow_upto(rid, want - 1) if want > 1 else 0
+                if grant:
+                    self._sync_block_table(r)
+                plan.append((slot, rid, grant + 1, cap))
 
-        # ladder floors (8 lanes / 4 pages): padding a tiny batch up to
-        # the floor costs almost nothing to execute, but every ladder
-        # rung below it is a whole XLA compile of the fused loop — the
-        # floors keep short-lived small engines from spending their
-        # entire run compiling rungs they graduate out of
-        if self._slot_state:
-            nb = self.n_slots
-            lane_of = {slot: slot for slot, _ in ready}
-        else:
-            nb = _pow2_bucket(len(ready), floor=8, cap=self.n_slots)
-            lane_of = {slot: j for j, (slot, _) in enumerate(ready)}
-        p_used = max(len(self.kv.block_table(rid)) for _, rid in ready)
-        pb = _pow2_bucket(p_used, floor=4, cap=self._max_pages)
+            # ladder floors (8 lanes / 4 pages): padding a tiny batch up
+            # to the floor costs almost nothing to execute, but every
+            # ladder rung below it is a whole XLA compile of the fused
+            # loop — the floors keep short-lived small engines from
+            # spending their entire run compiling rungs they graduate
+            # out of
+            if self._slot_state:
+                nb = self.n_slots
+                lane_of = {slot: slot for slot, _ in ready}
+            else:
+                nb = _pow2_bucket(len(ready), floor=8, cap=self.n_slots)
+                lane_of = {slot: j for j, (slot, _) in enumerate(ready)}
+            p_used = max(len(self.kv.block_table(rid)) for _, rid in ready)
+            pb = _pow2_bucket(p_used, floor=4, cap=self._max_pages)
+            span.set_metadata(nb=nb, pb=pb)
 
-        last = np.zeros(nb, np.int32)
-        cl = np.zeros(nb, np.int32)
-        tables = np.full((nb, pb), SCRATCH_BLOCK, np.int32)
-        budgets = np.zeros(nb, np.int32)
-        caps = np.ones(nb, np.int32)
-        eos = np.full(nb, -1, np.int32)
-        temps = np.zeros(nb, np.float32)
-        seeds = np.zeros(nb, np.uint32)
-        counters = np.zeros(nb, np.int32)
-        for slot, rid, budget, cap in plan:
-            r = self._requests[rid]
-            lane = lane_of[slot]
-            last[lane] = self._last_token[slot]
-            cl[lane] = self._cache_len[slot]
-            tables[lane] = self._block_tables[slot, :pb]
-            budgets[lane] = budget
-            caps[lane] = cap
-            eos[lane] = r.eos_token
-            temps[lane] = r.temperature
-            seeds[lane] = _rid_seed(rid)
-            counters[lane] = r.generated
+            lanes = self._fused_lanes(nb, pb)
+            last, cl, tables, budgets, caps, eos, temps, seeds, counters = \
+                lanes
+            for slot, rid, budget, cap in plan:
+                r = self._requests[rid]
+                lane = lane_of[slot]
+                last[lane] = self._last_token[slot]
+                cl[lane] = self._cache_len[slot]
+                tables[lane] = self._block_tables[slot, :pb]
+                budgets[lane] = budget
+                caps[lane] = cap
+                eos[lane] = r.eos_token
+                temps[lane] = r.temperature
+                seeds[lane] = _rid_seed(rid)
+                counters[lane] = r.generated
 
-        dev_args = (self.params, self._cache, jnp.asarray(last),
-                    jnp.asarray(cl), jnp.asarray(tables),
-                    jnp.asarray(budgets), jnp.asarray(caps),
-                    jnp.asarray(eos), jnp.asarray(temps),
-                    jnp.asarray(seeds), jnp.asarray(counters))
-        static = dict(n_steps=n_steps,
-                      all_greedy=bool((temps <= 0.0).all()))
-        def _abs(a):
-            # host-built args (tokens, tables, budgets) carry a default
-            # single-device placement; on a mesh the stash must record
-            # them as replicated or a later re-lower sees a device-set
-            # mismatch against the mesh-sharded params/pool
-            sh = a.sharding
-            if self.plan is not None and len(sh.device_set) != \
-                    self.plan.mesh.size:
-                sh = self.plan.replicated
-            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sh)
-        self._last_fused_call = (jax.tree.map(_abs, dev_args), static)
-        buf, emitted, fin, self._cache = self._fused_fn(*dev_args, **static)
+            static = dict(n_steps=n_steps,
+                          all_greedy=bool((temps <= 0.0).all()))
+            self._last_fused_call = (nb, pb, static)
+            buf, emitted, fin, self._cache = self._fused_fn(
+                self.params, self._cache, *map(jnp.asarray, lanes),
+                **static)
         # the ONE batched device->host transfer for this (multi-)step
-        buf, emitted, fin = jax.device_get((buf, emitted, fin))
+        with TraceAnnotation("engine.decode_wait"):
+            buf, emitted, fin = jax.device_get((buf, emitted, fin))
         self.metrics.decode_iterations += n_steps
         self.metrics.fused_steps += 1
+        self.metrics.decode_lanes += len(ready)
 
-        progressing, progressed = [], []
-        for slot, rid, _, _ in plan:
-            lane = lane_of[slot]
-            e = int(emitted[lane])
-            if e == 0:
-                continue
-            r = self._requests[rid]
-            toks = [int(t) for t in buf[lane, :e]]
-            r.output_tokens.extend(toks)
-            self._cache_len[slot] += e
-            self._last_token[slot] = toks[-1]
-            self.metrics.decode_tokens += e
-            if np.isnan(r.ttft):
-                r.ttft = self.clock() - r.arrival
-            if fin[lane]:
-                self._finish(r, reason="eos" if toks[-1] == r.eos_token
-                             else "length")
-                continue
-            progressing.append(rid)
-            progressed.append(r.generated)
-            # restore the reserve-one-ahead invariant for the next write;
-            # a False return is capacity pressure, relieved by forced
-            # eviction at the next select — same contract as the
-            # orchestrated path's per-token grow
-            if self.kv.grow(rid, 1):
-                self._sync_block_table(r)
-            else:
-                self.metrics.grow_failures += 1
-                self._needs_grow.add(rid)
-        self.scheduler.on_progress_many(progressing, progressed)
+        with TraceAnnotation("engine.decode_commit"):
+            progressing, progressed = [], []
+            for slot, rid, _, _ in plan:
+                lane = lane_of[slot]
+                e = int(emitted[lane])
+                if e == 0:
+                    continue
+                r = self._requests[rid]
+                toks = [int(t) for t in buf[lane, :e]]
+                r.output_tokens.extend(toks)
+                self._cache_len[slot] += e
+                self._last_token[slot] = toks[-1]
+                self.metrics.decode_tokens += e
+                if np.isnan(r.ttft):
+                    r.ttft = self.clock() - r.arrival
+                if fin[lane]:
+                    self._finish(r, reason="eos" if toks[-1] == r.eos_token
+                                 else "length")
+                    continue
+                progressing.append(rid)
+                progressed.append(r.generated)
+                # restore the reserve-one-ahead invariant for the next
+                # write; a False return is capacity pressure, relieved by
+                # forced eviction at the next select — same contract as
+                # the orchestrated path's per-token grow
+                if self.kv.grow(rid, 1):
+                    self._sync_block_table(r)
+                else:
+                    self.metrics.grow_failures += 1
+                    self._needs_grow.add(rid)
+            self.scheduler.on_progress_many(progressing, progressed)
+
+    @staticmethod
+    def _fused_lanes(nb: int, pb: int) -> tuple[np.ndarray, ...]:
+        """The fused step's host-built lane inputs for ``nb`` lanes and
+        ``pb`` table pages, every lane idle: (last, cl, tables, budgets,
+        caps, eos, temps, seeds, counters)."""
+        return (np.zeros(nb, np.int32), np.zeros(nb, np.int32),
+                np.full((nb, pb), SCRATCH_BLOCK, np.int32),
+                np.zeros(nb, np.int32), np.ones(nb, np.int32),
+                np.full(nb, -1, np.int32), np.zeros(nb, np.float32),
+                np.zeros(nb, np.uint32), np.zeros(nb, np.int32))
 
     # ------------------------------------------------------ compile budget
 
@@ -1121,13 +1145,28 @@ class ServingEngine:
             * n_steps_variants * 2
 
     def lower_fused_hlo(self) -> str | None:
-        """Compiled HLO text of the most recent fused-step call (None
-        before any decode).  Re-lowers from the stashed abstract args —
-        shape/dtype/sharding only, so this is safe after donation — for
-        the roofline bench's ``collective_bytes`` accounting."""
+        """Compiled HLO text of the most recent fused-step call's shapes
+        (None before any decode), for the roofline bench's
+        ``collective_bytes`` accounting.  Lowered from abstract args:
+        the engine's own params and pool as they are now (the pool
+        keeps its shapes and layout across donation) and the lane
+        inputs' shapes of that call."""
         if self._last_fused_call is None:
             return None
-        abstract, static = self._last_fused_call
+        nb, pb, static = self._last_fused_call
+
+        def spec(a, sharding):
+            return jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+
+        def placed(a):
+            return spec(a, a.sharding)
+        # host-built lane inputs take the default placement; on a mesh
+        # they are described as replicated, or the lowering sees a
+        # device-set mismatch against the mesh-sharded params/pool
+        host = None if self.plan is None else self.plan.replicated
+        abstract = (jax.tree.map(placed, self.params),
+                    jax.tree.map(placed, self._cache),
+                    *(spec(a, host) for a in self._fused_lanes(nb, pb)))
         return self._fused_fn.lower(*abstract, **static).compile().as_text()
 
     def sharding_report(self) -> dict | None:

@@ -52,6 +52,7 @@ from dataclasses import dataclass, field
 from typing import Callable
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from .engine import EngineStallError, ServingEngine
 from .request import RequestState, ServeRequest
@@ -300,32 +301,34 @@ class Gateway:
     def tick(self) -> None:
         """One gateway event-loop turn: reap completions, enforce
         deadlines, replay due retries, and pump the queues into the
-        engine (one coalesced ``submit_batch``)."""
-        now = self.clock()
-        self._reap()
-        self._enforce_deadlines(now)
-        self._reap()
-        # due retries re-enter admission (counted as retry attempts)
-        while self._retry and self._retry[0][0] <= now:
-            _, _, e = heapq.heappop(self._retry)
-            self.engine.metrics.retries += 1
-            accept: list[_Entry] = []
-            self._place(e, accept)
-            self._submit(accept)
-        # round-robin pump: fill the engine up to the in-flight bound
-        accept = []
-        bound = self._max_inflight()
-        while self.inflight + len(accept) < bound and self.queued > 0:
-            for _ in range(len(self._rr)):
-                tenant = self._rr[0]
-                self._rr.rotate(-1)
-                q = self._queues.get(tenant)
-                if q:
-                    accept.append(q.popleft())
+        engine (one coalesced ``submit_batch``), inside one
+        ``gateway.tick`` profiler span."""
+        with TraceAnnotation("gateway.tick"):
+            now = self.clock()
+            self._reap()
+            self._enforce_deadlines(now)
+            self._reap()
+            # due retries re-enter admission (counted as retry attempts)
+            while self._retry and self._retry[0][0] <= now:
+                _, _, e = heapq.heappop(self._retry)
+                self.engine.metrics.retries += 1
+                accept: list[_Entry] = []
+                self._place(e, accept)
+                self._submit(accept)
+            # round-robin pump: fill the engine up to the in-flight bound
+            accept = []
+            bound = self._max_inflight()
+            while self.inflight + len(accept) < bound and self.queued > 0:
+                for _ in range(len(self._rr)):
+                    tenant = self._rr[0]
+                    self._rr.rotate(-1)
+                    q = self._queues.get(tenant)
+                    if q:
+                        accept.append(q.popleft())
+                        break
+                else:
                     break
-            else:
-                break
-        self._submit(accept)
+            self._submit(accept)
 
     def step(self) -> int:
         """tick + one engine iteration."""

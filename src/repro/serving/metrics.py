@@ -33,6 +33,8 @@ class EngineMetrics:
     fused_steps: int = 0         # fused jitted (multi-)step calls issued;
                                  # each is ONE device dispatch + ONE
                                  # device->host bookkeeping transfer
+    decode_lanes: int = 0        # ready lanes summed over fused calls
+                                 # (and orchestrated iterations)
     completed: int = 0
     preemptions: int = 0
     forced_evictions: int = 0    # capacity-forced (decode-growth) evictions
@@ -66,12 +68,28 @@ class EngineMetrics:
             "goodput_tokens": self.decode_tokens - self.wasted_tokens,
         }
 
+    @staticmethod
+    def _waits(requests) -> dict:
+        """p50/p95 of the gateway wait (arrival to the engine's submit)
+        and the engine wait (submit to the first slot), each over the
+        requests that reached its end; NaN where none did."""
+        out = {}
+        for name, a, b in (("gateway_wait", "arrival", "submitted"),
+                           ("engine_wait", "submitted", "admitted")):
+            w = np.array([getattr(r, b, np.nan) - getattr(r, a, np.nan)
+                          for r in requests], np.float64)
+            w = w[np.isfinite(w)]
+            for q in (50, 95):
+                out[f"p{q}_{name}_s"] = _pct(w, q / 100) if w.size \
+                    else float("nan")
+        return out
+
     def summary(self, requests) -> dict:
         done = [r for r in requests
                 if np.isfinite(getattr(r, "ttlt", np.nan))]
         if not done:
             return {"completed": 0, "calibration": self.calibration,
-                    **self._failure_counters()}
+                    **self._failure_counters(), **self._waits(requests)}
         ttft = np.array([r.ttft for r in done])
         ttlt = np.array([r.ttlt for r in done])
         gen = np.array([r.generated for r in done], np.float64)
@@ -100,6 +118,7 @@ class EngineMetrics:
             "decode_iterations": self.decode_iterations,
             "decode_tokens": self.decode_tokens,
             "fused_steps": self.fused_steps,
+            "decode_lanes": self.decode_lanes,
             "preemptions": self.preemptions,
             "forced_evictions": self.forced_evictions,
             "grow_failures": self.grow_failures,
@@ -108,4 +127,5 @@ class EngineMetrics:
             "modeled_swap_s": self.modeled_swap_s,
             "calibration": self.calibration,
             **self._failure_counters(),
+            **self._waits(requests),
         }

@@ -45,6 +45,12 @@ class ServeRequest:
     prefill_pos: int = 0              # context tokens whose KV is resident
     ttft: float = float("nan")
     ttlt: float = float("nan")
+    # the engine's clock when submit_batch took the request, and the
+    # first time it was bound to a slot (readmissions after a
+    # preemption leave it): arrival -> submitted is the gateway's
+    # queue, submitted -> admitted the scheduler's waiting set
+    submitted: float = float("nan")
+    admitted: float = float("nan")
     n_preemptions: int = 0
     n_swap_restores: int = 0          # readmissions that skipped re-prefill
     finish_reason: str = ""           # why the request reached its terminal
