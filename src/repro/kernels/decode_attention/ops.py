@@ -1,4 +1,7 @@
-"""jit'd wrapper for the flash-decode kernel (ref fallback off-TPU)."""
+"""jit'd wrappers for the flash-decode kernels (ref fallback off-TPU).
+
+The paged kernel without partials has one dispatch point,
+``models.attention.decode_attention_paged``."""
 
 from __future__ import annotations
 
@@ -8,14 +11,11 @@ import jax
 import jax.numpy as jnp
 
 from ..bucketing import pow2_bucket
-from .kernel import (decode_attention_kernel, decode_attention_paged_kernel,
-                     decode_attention_paged_lse_kernel)
+from .kernel import decode_attention_kernel, decode_attention_paged_lse_kernel
 from .ref import (decode_attention_paged_lse_reference,
-                  decode_attention_paged_reference,
                   decode_attention_reference)
 
-__all__ = ["decode_attention_op", "decode_attention_paged_op",
-           "decode_attention_paged_lse_op"]
+__all__ = ["decode_attention_op", "decode_attention_paged_lse_op"]
 
 
 @functools.partial(jax.jit, static_argnames=("window", "block_s",
@@ -43,45 +43,17 @@ def decode_attention_op(q, k_cache, v_cache, cache_len, *, window: int = 0,
 
 
 @functools.partial(jax.jit, static_argnames=("window", "force_pallas"))
-def decode_attention_paged_op(q, k_pool, v_pool, block_tables, cache_len, *,
-                              window: int = 0, force_pallas: bool = False):
-    """Paged flash-decode: q (B, H, dh); pools (n_pages, page, KV, dh);
-    block_tables (B, P) int32; cache_len (B,).  The kernel's KV grid step
-    is the page itself — block tables replace any padding logic.
-
-    The logical-page axis is padded to a pow2 bucket before the kernel
-    call: the padded table entries point at physical page 0 (the serving
-    engine's scratch page) and sit past every row's ``cache_len``, so
-    they are masked out — the kernel's grid/index-map signature stays on
-    the bounded bucket ladder no matter how callers size their tables.
-
-    Per-shard invariant (docs/sharded_serving.md): KV is a pure batch
-    dim here — the kernel never contracts or reduces over it — so a
-    mesh that splits the pool over kv-heads runs this exact kernel on
-    per-shard pool slices with an unchanged grid; the block tables it
-    indexes with are global and shard-invariant."""
-    native = jax.default_backend() == "tpu"
-    if not native and not force_pallas:
-        return decode_attention_paged_reference(
-            q, k_pool, v_pool, block_tables, cache_len, window=window)
-    p_max = block_tables.shape[1]
-    pb = pow2_bucket(p_max)
-    if pb != p_max:
-        block_tables = jnp.pad(block_tables, ((0, 0), (0, pb - p_max)))
-    return decode_attention_paged_kernel(
-        q, k_pool, v_pool, block_tables.astype(jnp.int32),
-        cache_len.astype(jnp.int32), window=window, interpret=not native)
-
-
-@functools.partial(jax.jit, static_argnames=("window", "force_pallas"))
 def decode_attention_paged_lse_op(q, k_pool, v_pool, block_tables,
                                   cache_len, *, window: int = 0,
                                   force_pallas: bool = False):
     """Partial paged flash-decode returning ``(out, lse)``.
 
-    Same operands and padding contract as ``decode_attention_paged_op``,
-    but ``out`` is normalized over only the pages reachable through THIS
-    call's block tables and ``lse`` (B, H) f32 is their log-sum-exp.
+    q (B, H, dh); pools (n_pages, page, KV, dh); block_tables (B, P)
+    int32; cache_len (B,).  The logical-page axis is padded to a pow2
+    bucket with entries on physical page 0 (the serving engine's scratch
+    page), past every row's ``cache_len``.  ``out`` is normalized over
+    only the pages reachable through THIS call's block tables and
+    ``lse`` (B, H) f32 is their log-sum-exp.
     This is the per-stripe building block for LSE-combined sharded
     attention: when kv heads don't divide the mesh, each shard runs this
     op over its stripe of the logical page axis and the partials merge

@@ -10,8 +10,14 @@ kernel replaces it via repro.kernels.flash_attention.ops.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ..kernels.decode_attention.kernel import decode_attention_paged_kernel
+from ..sharding.context import paged_pool_sharding
 
 __all__ = ["gqa_attention", "decode_attention", "decode_attention_paged",
            "encoder_attention", "combine_lse_partials"]
@@ -125,8 +131,10 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, cache_len, *,
                            window: int = 0, n_splits: int = 1,
                            constrain_split=None):
     """One-token decode attention over a *paged* KV pool (vLLM block-table
-    indirection, jnp twin of repro.kernels.decode_attention's paged
-    kernel).
+    indirection).  On TPU this is repro.kernels.decode_attention's paged
+    kernel, which reads only each row's live pages (per shard under a
+    mesh plan); on the CPU and for ``n_splits > 1`` it is the jnp gather
+    below, the kernel's reference.
 
     q: (B, 1, H, dh); k_pool/v_pool: (n_pages, page, KV, dh) — one pool
     shared by the whole batch; block_tables: (B, P) int32 mapping logical
@@ -143,10 +151,12 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, cache_len, *,
     independent lanes — so the fused engine step can gather active slots
     into pow2 batch buckets without perturbing any real lane's logits.
 
-    Shard-invariant too: every einsum batches over the KV dim and
-    contracts only dh/sequence, so a pool sharded over kv-heads
-    (serving.sharded) computes per-shard slices of the identical GEMMs —
-    the mesh engine's bit-identity rests on this.
+    Shard-invariant too: both paths treat the KV dim as a batch dim and
+    contract only dh/sequence — the gather's einsums batch over it, and
+    the kernel runs per shard with the whole pool's pages per block —
+    so a pool sharded over kv-heads (serving.sharded) computes per-shard
+    slices of the identical arithmetic; the mesh engine's bit-identity
+    rests on this.
 
     ``n_splits > 1`` (the efficient-mode LSE fallback, installed via
     ``sharding.context`` when kv heads don't divide the mesh) splits the
@@ -164,6 +174,10 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, cache_len, *,
         return _decode_attention_paged_split(
             q, k_pool, v_pool, block_tables, cache_len, window=window,
             n_splits=n_splits, constrain_split=constrain_split)
+    if jax.default_backend() == "tpu":
+        return _decode_attention_paged_kernel(q, k_pool, v_pool,
+                                              block_tables, cache_len,
+                                              window)
     b = q.shape[0]
     n_pages, page, kvh, dh = k_pool.shape
     h = q.shape[2]
@@ -171,8 +185,7 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, cache_len, *,
     p_max = block_tables.shape[1]
     s_log = p_max * page
     scale = dh ** -0.5
-    # gather each row's logical cache from the pool (reference path; the
-    # Pallas kernel streams physical pages instead of materializing this)
+    # gather each row's logical cache from the pool (reference path)
     tok = (block_tables.astype(jnp.int32) * page)[:, :, None] \
         + jnp.arange(page, dtype=jnp.int32)[None, None, :]   # (B, P, page)
     tok = tok.reshape(b, s_log)
@@ -194,6 +207,26 @@ def decode_attention_paged(q, k_pool, v_pool, block_tables, cache_len, *,
                      preferred_element_type=jnp.float32)
     out = out / jnp.maximum(p.sum(axis=-1), 1e-30)[..., None]
     return out.reshape(b, 1, h, dh).astype(q.dtype)
+
+
+def _decode_attention_paged_kernel(q, k_pool, v_pool, block_tables,
+                                   cache_len, window):
+    """The Pallas paged kernel, reading each lane's live pages where they
+    lie in the pool.  Under a mesh serving plan it runs under
+    ``shard_map`` on each shard's KV heads and the query heads grouped
+    over them (all of them where the pool is replicated), blocking pages
+    as the whole pool's kernel does, so each head's result is the
+    single-device kernel's bit for bit."""
+    run = functools.partial(decode_attention_paged_kernel, window=window,
+                            pool_kv_heads=k_pool.shape[2])
+    pool = paged_pool_sharding()
+    if pool is not None:
+        kv = pool.spec[3]
+        heads, pages = P(None, kv, None), P(None, None, kv, None)
+        run = jax.shard_map(run, mesh=pool.mesh,
+                            in_specs=(heads, pages, pages, P(), P()),
+                            out_specs=heads, check_vma=False)
+    return run(q[:, 0], k_pool, v_pool, block_tables, cache_len)[:, None]
 
 
 def _decode_attention_paged_split(q, k_pool, v_pool, block_tables,

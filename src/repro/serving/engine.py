@@ -1081,6 +1081,12 @@ class ServingEngine:
         self.metrics.decode_iterations += n_steps
         self.metrics.fused_steps += 1
         self.metrics.decode_lanes += len(ready)
+        # pages holding each ready lane's context as the call attends it
+        # (its cache plus the token it writes first)
+        self.metrics.decode_kv_pages += sum(
+            int(cl[lane_of[slot]]) // self.block_size + 1
+            for slot, _ in ready)
+        self.metrics.decode_kv_pages_padded += nb * pb
 
         with TraceAnnotation("engine.decode_commit"):
             progressing, progressed = [], []

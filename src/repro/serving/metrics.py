@@ -35,6 +35,10 @@ class EngineMetrics:
                                  # device->host bookkeeping transfer
     decode_lanes: int = 0        # ready lanes summed over fused calls
                                  # (and orchestrated iterations)
+    decode_kv_pages: int = 0     # KV pages holding the ready lanes'
+                                 # context, summed over fused calls
+    decode_kv_pages_padded: int = 0  # lanes x table pages (nb x pb)
+                                 # summed over fused calls
     completed: int = 0
     preemptions: int = 0
     forced_evictions: int = 0    # capacity-forced (decode-growth) evictions
@@ -119,6 +123,12 @@ class EngineMetrics:
             "decode_tokens": self.decode_tokens,
             "fused_steps": self.fused_steps,
             "decode_lanes": self.decode_lanes,
+            "decode_kv_pages": self.decode_kv_pages,
+            "decode_kv_pages_padded": self.decode_kv_pages_padded,
+            # the share of the padded tables that paged attention reads
+            "decode_kv_read_share": (
+                self.decode_kv_pages / self.decode_kv_pages_padded
+                if self.decode_kv_pages_padded else float("nan")),
             "preemptions": self.preemptions,
             "forced_evictions": self.forced_evictions,
             "grow_failures": self.grow_failures,

@@ -12,8 +12,9 @@ one engine:
     transparently: a payload is the full-head numpy array, so swap-mode
     preemption, CoW prefix sharing, and cluster migration are untouched.
     The attention einsums inherit the pool's sharding and parallelize
-    over the kv-head batch dim — the decode bottleneck (pool bandwidth)
-    scales with the mesh;
+    over the kv-head batch dim (on TPU the paged decode kernel runs per
+    shard under ``shard_map``, following ``context()``'s pool layout) —
+    the decode bottleneck (pool bandwidth) scales with the mesh;
   * expert-parallel MoE — the (E, C, D) capacity buffer and per-expert
     weights shard over 'model'; the router and the K-way weighted
     combine stay replicated;
@@ -44,8 +45,9 @@ the whole dataflow from sharding propagation.  Per-token FLOPs shrink
 bit-identical at tp=1, greedy-token match >= 0.999 at tp>1.
 
 Execution model: jit + ``NamedSharding`` (GSPMD), not a hand-written
-``shard_map`` — the engine's host loop, global logical shapes, pow2
-bucket ladders, and buffer donation all carry over unchanged; the plan
+``shard_map`` (paged decode attention's Pallas kernel aside) — the
+engine's host loop, global logical shapes, pow2 bucket ladders, and
+buffer donation all carry over unchanged; the plan
 only (a) places params and pool once, (b) installs trace-scoped hooks
 (``gather_model`` / ``constrain_expert_buf``) around the engine's jit
 call sites, and (c) pins cache-typed jit outputs back to the pool
@@ -212,7 +214,8 @@ class ShardingPlan:
                                 q_heads_spec=self.q_heads,
                                 kv_heads_spec=self.kv_heads,
                                 attn_splits=self.attn_splits,
-                                split_spec=self.split_spec)
+                                split_spec=self.split_spec,
+                                pool_sharding=self.kv_pool)
 
     def wrap_jit(self, fn, **jit_kwargs):
         """jax.jit that traces under ``context()``.  Forwards the

@@ -19,7 +19,7 @@ _state = threading.local()
 __all__ = ["constrain_activations", "activation_sharding",
            "gather_model", "serving_sharding", "constrain_q_heads",
            "constrain_kv_heads", "attn_split_count",
-           "constrain_attn_split"]
+           "constrain_attn_split", "paged_pool_sharding"]
 
 
 def constrain_activations(h):
@@ -132,16 +132,26 @@ def constrain_attn_split(x):
     return jax.lax.with_sharding_constraint(x, spec)
 
 
+def paged_pool_sharding():
+    """The serving plan's (L, n_pages, page, KV, dh) paged-pool
+    ``NamedSharding`` while its hooks are installed (inside
+    ``serving_sharding``); None on a single device."""
+    return getattr(_state, "pool_sharding", None)
+
+
 @contextlib.contextmanager
 def serving_sharding(gather_fn, expert_spec=None, q_heads_spec=None,
-                     kv_heads_spec=None, attn_splits=1, split_spec=None):
+                     kv_heads_spec=None, attn_splits=1, split_spec=None,
+                     pool_sharding=None):
     """Install the serving-decode hooks around a jit trace: ``gather_fn``
     backs ``gather_model``; ``expert_spec`` (optional) backs
     ``constrain_expert_buf`` so the MoE capacity buffer stays
     expert-sharded.  The efficient-mode plan additionally installs
     ``q_heads_spec``/``kv_heads_spec`` (column-parallel projection
     outputs pinned head-sharded), and ``attn_splits``/``split_spec``
-    (the LSE page-split fallback when heads don't divide).  Scoped: the
+    (the LSE page-split fallback when heads don't divide).
+    ``pool_sharding`` is the paged pool's layout, which paged decode
+    attention follows (``paged_pool_sharding``).  Scoped: the
     engine enters this only around its jit call sites, so plain
     single-device engines in the same process never see the
     constraints."""
@@ -151,12 +161,14 @@ def serving_sharding(gather_fn, expert_spec=None, q_heads_spec=None,
     prev_kv = getattr(_state, "kv_heads_spec", None)
     prev_n = getattr(_state, "attn_splits", 1)
     prev_sp = getattr(_state, "split_spec", None)
+    prev_pool = getattr(_state, "pool_sharding", None)
     _state.gather_fn = gather_fn
     _state.expert_spec = expert_spec
     _state.q_heads_spec = q_heads_spec
     _state.kv_heads_spec = kv_heads_spec
     _state.attn_splits = attn_splits
     _state.split_spec = split_spec
+    _state.pool_sharding = pool_sharding
     try:
         yield
     finally:
@@ -166,6 +178,7 @@ def serving_sharding(gather_fn, expert_spec=None, q_heads_spec=None,
         _state.kv_heads_spec = prev_kv
         _state.attn_splits = prev_n
         _state.split_spec = prev_sp
+        _state.pool_sharding = prev_pool
 
 
 @contextlib.contextmanager

@@ -8,8 +8,9 @@ import numpy as np
 import pytest
 
 from repro.core import gittins_index_batch
-from repro.kernels.decode_attention.ops import (decode_attention_op,
-                                                decode_attention_paged_op)
+from repro.kernels.decode_attention.kernel import \
+    decode_attention_paged_kernel
+from repro.kernels.decode_attention.ops import decode_attention_op
 from repro.kernels.decode_attention.ref import (
     decode_attention_paged_reference, decode_attention_reference)
 from repro.kernels.flash_attention.ops import flash_attention
@@ -75,8 +76,8 @@ def test_decode_attention_vs_oracle(B, S, H, KV, dh, window, blk, dtype):
 ])
 def test_paged_decode_attention_vs_oracle(B, H, KV, dh, page, P, n_pages,
                                           window, dtype):
-    """Block-table indirection kernel (scalar-prefetch index maps) vs the
-    gather-based oracle, non-contiguous physical pages."""
+    """Paged kernel (pages copied through the scalar-prefetched block
+    table) vs the gather-based oracle, non-contiguous physical pages."""
     rng = np.random.default_rng(7)
     q = jnp.asarray(rng.normal(0, 1, (B, H, dh)), dtype)
     kp = jnp.asarray(rng.normal(0, 1, (n_pages, page, KV, dh)), dtype)
@@ -84,12 +85,66 @@ def test_paged_decode_attention_vs_oracle(B, H, KV, dh, page, P, n_pages,
     bt = jnp.asarray(rng.permutation(np.arange(1, n_pages))[:B * P]
                      .reshape(B, P), jnp.int32)
     cl = jnp.asarray(rng.integers(1, P * page, (B,)), jnp.int32)
-    got = decode_attention_paged_op(q, kp, vp, bt, cl, window=window,
-                                    force_pallas=True)
+    got = decode_attention_paged_kernel(q, kp, vp, bt, cl, window=window,
+                                        interpret=True)
     want = decode_attention_paged_reference(q, kp, vp, bt, cl,
                                             window=window)
     np.testing.assert_allclose(np.asarray(got, np.float32),
                                np.asarray(want, np.float32), **_tol(dtype))
+
+
+# The paged kernel at served widths (dh 128, page 16, bf16 pools):
+# (H, KV, table pages P, cache_len of each real lane, window).  Pages
+# per block follow from the page's bytes: 16 at qwen2-1.5b's 2 KV heads
+# (a block is 256 tokens), 2 at olmoe-1b-7b's 16 (32 tokens).  Every
+# batch also carries a pad lane: one token on the scratch page.
+LIVE_PAGES = [
+    # 1 token, a partial last page, a block boundary, the full rung
+    pytest.param(12, 2, 32, [1, 40, 256, 512, 300], 0, id="qwen2-ragged"),
+    pytest.param(16, 16, 8, [1, 40, 32, 128, 77], 0, id="olmoe-ragged"),
+    pytest.param(12, 2, 32, [1, 40, 256, 512, 300], 40, id="qwen2-window"),
+    pytest.param(16, 16, 8, [1, 40, 32, 128, 77], 24, id="olmoe-window"),
+    # pages per block do not divide the table: 16 into 24, 2 into 5
+    pytest.param(12, 2, 24, [384, 257, 1, 100], 0, id="qwen2-ppb-24"),
+    pytest.param(16, 16, 5, [80, 33, 1], 0, id="olmoe-ppb-5"),
+]
+
+
+@pytest.mark.parametrize("H,KV,P,lens,window", LIVE_PAGES)
+def test_paged_kernel_reads_live_pages_vs_f32_oracle(H, KV, P, lens,
+                                                     window):
+    """The paged kernel against the float32 gather oracle, with block
+    tables scattered out of order over the pool.  Every page that holds
+    no live token of any lane (past a lane's cache_len, before its
+    window, or in no table) is NaN: a kernel that read one would return
+    NaN."""
+    rng = np.random.default_rng(11)
+    dh, page, n_pages = 128, 16, 200
+    lens = np.array(lens + [1], np.int32)            # last: the pad lane
+    b = len(lens)
+    perm = rng.permutation(np.arange(1, n_pages))
+    bt = perm[:(b - 1) * P].reshape(b - 1, P)
+    bt = np.concatenate([bt, np.zeros((1, P), np.int64)]).astype(np.int32)
+    kp = rng.normal(0, 1, (n_pages, page, KV, dh)).astype(np.float32)
+    vp = rng.normal(0, 1, (n_pages, page, KV, dh)).astype(np.float32)
+    live = np.zeros(n_pages, bool)
+    for row, n in zip(bt, lens):
+        first = max(n - window, 0) // page if window else 0
+        live[row[first:-(-n // page)]] = True
+    q = jnp.asarray(rng.normal(0, 1, (b, H, dh)), jnp.bfloat16)
+    kb = jnp.asarray(kp, jnp.bfloat16)
+    vb = jnp.asarray(vp, jnp.bfloat16)
+    want = decode_attention_paged_reference(
+        q.astype(jnp.float32), kb.astype(jnp.float32),
+        vb.astype(jnp.float32), jnp.asarray(bt), jnp.asarray(lens),
+        window=window)
+    dead = jnp.asarray(~live)[:, None, None, None]
+    got = decode_attention_paged_kernel(
+        q, jnp.where(dead, jnp.nan, kb), jnp.where(dead, jnp.nan, vb),
+        jnp.asarray(bt), jnp.asarray(lens), window=window, interpret=True)
+    assert got.dtype == jnp.bfloat16
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want), rtol=1e-2, atol=1e-2)
 
 
 @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])

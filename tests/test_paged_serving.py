@@ -14,6 +14,8 @@ The load-bearing assertions:
     ServiceModel.swap_time / block-table accounting.
 """
 
+from contextlib import nullcontext
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -391,3 +393,94 @@ def test_prefill_time_chunked_consistent():
     assert sm.prefill_time_chunked(700, 256) == pytest.approx(total)
     # chunking trades fixed overhead for a smaller attention term
     assert sm.prefill_time_chunked(700, 256) != sm.prefill_time(700)
+
+
+# ------------------------------------- paged attention dispatch, counters
+
+@pytest.mark.parametrize("backend,n_splits,plan,kernel", [
+    ("tpu", 1, False, True),
+    ("cpu", 1, False, False),
+    ("tpu", 2, False, False),      # efficient mode's page split
+    ("tpu", 1, True, True),        # a mesh plan: the kernel per shard
+], ids=["tpu", "cpu", "tpu-split", "tpu-mesh-plan"])
+def test_paged_decode_dispatch(monkeypatch, backend, n_splits, plan,
+                               kernel):
+    """On TPU ``decode_attention_paged`` runs the Pallas kernel (here in
+    interpret mode) and agrees with the gather; under a mesh plan the
+    kernel runs per shard and equals the single-device kernel bit for
+    bit.  The CPU and the split path keep the gather."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from repro.kernels.decode_attention import kernel as kmod
+    from repro.models import attention
+    from repro.sharding.context import serving_sharding
+    calls = []
+
+    def spy(*args, **kw):
+        calls.append(args[1].shape)
+        return kmod.decode_attention_paged_kernel(*args, interpret=True,
+                                                  **kw)
+
+    rng = np.random.default_rng(5)
+    B, H, KV, dh, page, P_, n_pages = 3, 12, 2, 128, 16, 4, 16
+    q = jnp.asarray(rng.normal(0, 1, (B, 1, H, dh)), jnp.bfloat16)
+    kp = jnp.asarray(rng.normal(0, 1, (n_pages, page, KV, dh)),
+                     jnp.bfloat16)
+    vp = jnp.asarray(rng.normal(0, 1, (n_pages, page, KV, dh)),
+                     jnp.bfloat16)
+    bt = jnp.asarray(rng.permutation(np.arange(1, n_pages))[:B * P_]
+                     .reshape(B, P_), jnp.int32)
+    cl = jnp.asarray([1, 33, 64], jnp.int32)
+    want = attention.decode_attention_paged(q, kp, vp, bt, cl)
+
+    monkeypatch.setattr(attention, "decode_attention_paged_kernel", spy)
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    mesh = Mesh(np.array(jax.devices()[:1]), ("model",))
+    pool = NamedSharding(mesh, P(None, None, None, "model", None))
+    with (serving_sharding(lambda x: x, pool_sharding=pool) if plan
+          else nullcontext()):
+        got = attention.decode_attention_paged(q, kp, vp, bt, cl,
+                                               n_splits=n_splits)
+    assert calls == ([kp.shape] if kernel else [])
+    assert got.shape == want.shape and got.dtype == want.dtype
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32),
+                               rtol=1e-2, atol=1e-2)
+    if plan:
+        one = spy(q[:, 0], kp, vp, bt, cl)[:, None]
+        np.testing.assert_array_equal(np.asarray(got, np.float32),
+                                      np.asarray(one, np.float32))
+
+
+def test_decode_kv_page_counters():
+    """``decode_kv_pages`` sums the pages holding each ready lane's
+    context over fused calls, ``decode_kv_pages_padded`` the calls'
+    nb x pb, and ``summary()`` reports their ratio."""
+    cfg = get_config("llama3.2-1b", reduced=True)
+    o = OraclePredictor()
+    o.register("p", LengthDistribution(np.array([4]), np.array([1.0])))
+    eng = ServingEngine(
+        model=build_model(cfg),
+        scheduler=Scheduler(policy=make_policy("fcfs"), predictor=o),
+        n_slots=4, max_seq_len=96, block_size=8, seed=0)
+    # prompts of 5, 13 and 20 tokens, 4 new tokens each: every request
+    # prefills in the first step and the three decode in lockstep
+    reqs = [ServeRequest(f"r{i}", "p", list(range(3, 3 + n)),
+                         max_new_tokens=4, eos_token=-1,
+                         arrival=i * 1e-3)
+            for i, n in enumerate((5, 13, 20))]
+    eng.submit_batch(reqs)
+    m = eng.metrics
+    seen = []
+    while eng.has_work:
+        eng.step()
+        seen.append((m.fused_steps, m.decode_kv_pages,
+                     m.decode_kv_pages_padded))
+    # prefill caches all but the last prompt token, so four fused calls
+    # attend contexts of (5, 13, 20) up to (8, 16, 23) tokens: pages
+    # (1 + 2 + 3) each call; 3 lanes pad to nb 4 and the longest table
+    # (3 pages) to the ladder's floor of 4 pages
+    assert seen == [(1, 6, 16), (2, 12, 32), (3, 18, 48), (4, 24, 64)]
+    s = eng.metrics.summary(reqs)
+    assert (s["decode_kv_pages"], s["decode_kv_pages_padded"]) == (24, 64)
+    assert s["decode_kv_read_share"] == pytest.approx(24 / 64)

@@ -161,6 +161,71 @@ def test_mesh_parity(fam, step_mode, pmode, tp):
         assert 0 < eng.fused_compile_count <= eng.max_fused_compiles()
 
 
+@pytest.mark.parametrize("tp", [1, 2, 4])
+@pytest.mark.parametrize("fam", ["dense", "moe"])
+def test_mesh_parity_paged_kernel(monkeypatch, fam, tp):
+    """On TPU paged decode attention is the Pallas kernel, run per shard
+    under a mesh plan: the sharded streams still equal the single-device
+    engine's.  The backend is reported as TPU and the kernel runs in
+    interpret mode."""
+    _need_devices(tp)
+    from repro.kernels.decode_attention import kernel as kmod
+    from repro.models import attention
+    calls = []
+
+    def kernel(*args, **kw):
+        calls.append(args[1].shape)
+        return kmod.decode_attention_paged_kernel(*args, interpret=True,
+                                                  **kw)
+
+    monkeypatch.setattr(attention, "decode_attention_paged_kernel", kernel)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    _, want = _run(fam, step_mode="fused", tp=None)
+    assert calls
+    calls.clear()
+    eng, got = _run(fam, step_mode="fused", tp=tp)
+    assert got == want, f"{fam}/tp={tp} diverged with the paged kernel"
+    assert eng.metrics.preemptions > 0
+    # each shard's kernel sees its own KV heads
+    n_kv = eng.model.cfg.n_kv_heads
+    assert calls and all(shape[2] == n_kv // tp for shape in calls)
+
+
+@pytest.mark.parametrize("tp", [2, 4])
+def test_mesh_paged_kernel_per_shard_bit_identical(monkeypatch, tp):
+    """olmoe-1b-7b's decode attention widths (16 query and 16 KV heads,
+    dh 128, 16-token pages, where a block holds 2 pages of the whole
+    pool but would hold 2 * tp of one shard's): the kernel under a mesh
+    plan returns the single-device kernel's output bit for bit."""
+    _need_devices(tp)
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding
+
+    from repro.kernels.decode_attention import kernel as kmod
+    from repro.models import attention
+    from repro.sharding.context import serving_sharding
+
+    kernel = functools.partial(kmod.decode_attention_paged_kernel,
+                               interpret=True)
+    rng = np.random.default_rng(3)
+    b, h, kv, dh, page, p_max, n_pages = 4, 16, 16, 128, 16, 8, 40
+    q = jnp.asarray(rng.normal(0, 1, (b, 1, h, dh)), jnp.bfloat16)
+    kp, vp = (jnp.asarray(rng.normal(0, 1, (n_pages, page, kv, dh)),
+                          jnp.bfloat16) for _ in range(2))
+    bt = jnp.asarray(rng.permutation(np.arange(1, n_pages))[:b * p_max]
+                     .reshape(b, p_max), jnp.int32)
+    cl = jnp.asarray([1, 40, 100, 128], jnp.int32)
+    want = kernel(q[:, 0], kp, vp, bt, cl)
+
+    monkeypatch.setattr(attention, "decode_attention_paged_kernel", kernel)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    pool = NamedSharding(make_local_mesh(tp=tp), POOL_SPEC_SHARDED)
+    with serving_sharding(lambda x: x, pool_sharding=pool):
+        got = jax.jit(attention.decode_attention_paged)(q, kp, vp, bt, cl)
+    np.testing.assert_array_equal(np.asarray(got[:, 0], np.float32),
+                                  np.asarray(want, np.float32))
+
+
 @pytest.mark.parametrize("fam", ["dense", "moe"])
 def test_mesh_multi_step_fused(fam):
     """decode_steps=4 batches four decode tokens per host round-trip
